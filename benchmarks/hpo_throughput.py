@@ -432,7 +432,11 @@ def _feed_scheduler(cfgs):
 def _probe_sharded(arch: str, n_trials: int, population: int, steps: int,
                    batch: int, seq: int, seed: int) -> dict:
     """Time vmapped + sharded inside a fresh process with a forced
-    MESH_DEVICES-wide virtual CPU mesh (must happen before jax init)."""
+    MESH_DEVICES-wide virtual CPU mesh (must happen before jax init).
+
+    The child runs on the CPU by design (``JAX_PLATFORMS=cpu``): this parent
+    has already touched JAX and holds the accelerator, and a child that
+    asked for it would fail or hang."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
@@ -1105,6 +1109,8 @@ def _recovery_row(arch: str, population: int, batch: int, seq: int,
         }
 
         # -- (c) CLI kill at an event boundary + --resume ----------------------
+        # the CLI children run on the CPU by design: this process already
+        # holds the accelerator, and one chip serves one process at a time
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
         env.pop("XLA_FLAGS", None)

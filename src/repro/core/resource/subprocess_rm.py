@@ -4,6 +4,12 @@ The job's BasicConfig is written to ``<workdir>/job_<id>.json``; the user's
 self-executable script runs as ``python <script> <json>``; stdout is parsed
 for the ``print_result`` line.  The resource id is exported as
 ``REPRO_RESOURCE`` (the CUDA_VISIBLE_DEVICES analogue — on TPU the slice name).
+
+One process per chip: this manager never imports JAX, so a script job may
+take the accelerator only while the controlling process stays off JAX too
+(a parent that has touched JAX holds the chip, and a child that then asks
+for it fails or hangs).  A controller that also trains in-process must give
+its script jobs ``JAX_PLATFORMS=cpu``.
 """
 from __future__ import annotations
 

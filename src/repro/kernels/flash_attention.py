@@ -5,10 +5,12 @@ TPU-native adaptation of the flash algorithm:
 * grid = (batch, q_heads, num_q_blocks, num_kv_blocks) — on TPU the last grid
   dimension iterates sequentially on-core, so the online-softmax state for one
   (b, h, iq) lives in VMEM scratch across the kv sweep; no HBM round-trips.
-* BlockSpec tiling: q tile (block_q, head_dim) and k/v tiles
-  (block_kv, head_dim) are staged HBM->VMEM by Pallas; the (block_q, block_kv)
-  score tile exists only in VMEM/VREGs and is immediately consumed by the MXU
-  for the P·V partial product — the memory win the roofline counts.
+* BlockSpec tiling: the kernel runs heads-major, (B, H, S, D), so the last
+  two block dims are the q tile (block_q, head_dim) and k/v tiles
+  (block_kv, head_dim) that TPU tiling requires, staged HBM->VMEM by
+  Pallas; the (block_q, block_kv) score tile exists only in VMEM/VREGs and
+  is immediately consumed by the MXU for the P·V partial product — the
+  memory win the roofline counts.
 * GQA: the q-head grid coordinate maps to kv head h // group via the k/v
   index_maps — kv tiles are fetched once per group on TPU (grid order makes
   consecutive h hit the same kv tile).
@@ -37,7 +39,7 @@ NEG_INF = -1e30
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref,           # (1, block_q/kv, 1, D) VMEM tiles
+    q_ref, k_ref, v_ref,           # (1, 1, block_q/kv, D) VMEM tiles
     o_ref, lse_ref,                # outputs
     m_scr, l_scr, acc_scr,         # VMEM scratch carried across the kv sweep
     *,
@@ -60,9 +62,9 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale          # (bq, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                  # (bk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)                  # (bk, Dv)
+    q = q_ref[0, 0].astype(jnp.float32) * scale                # (bq, D)
+    k = k_ref[0, 0].astype(jnp.float32)                        # (bk, D)
+    v = v_ref[0, 0].astype(jnp.float32)                        # (bk, Dv)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -79,25 +81,27 @@ def _flash_kernel(
         mask &= k_pos > q_pos - window
     s = jnp.where(mask, s, NEG_INF)
 
+    # row statistics stay (bq, 1) columns: the layout the lane-wise
+    # reductions produce and the (bq, D) accumulator broadcasts against
     m_prev = m_scr[...]
     l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-    p = jnp.where(mask, jnp.exp(s - m_safe[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
     corr = jnp.where(m_prev > NEG_INF / 2, jnp.exp(m_prev - m_safe), 0.0)
     m_scr[...] = m_new
-    l_scr[...] = l_prev * corr + p.sum(axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+    l_scr[...] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     @pl.when(ik == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
         m = m_scr[...]
         lse = jnp.where(m > NEG_INF / 2, m + jnp.log(l), NEG_INF)
-        lse_ref[0, 0, :] = lse.astype(lse_ref.dtype)
+        lse_ref[0, 0] = lse.astype(lse_ref.dtype)
 
 
 @functools.partial(
@@ -139,8 +143,13 @@ def _flash_fwd_pallas(
         scale=scale, causal=causal, window=window, softcap=softcap,
         block_q=block_q, block_kv=block_kv, nk=nk, sq=Sq, sk=Sk,
     )
-    out, lse = _call(kernel, grid, q, k, v, B, Sq, H, D, Dv, pad_q, block_q, block_kv, g, interpret)
-    return out[:, :Sq], lse[..., :Sq]
+    # heads-major inside the kernel: Mosaic tiles the last two block dims,
+    # which must then be (seq block, head_dim) — a head block of 1 in the
+    # second-minor position of (B, S, H, D) is not a legal TPU tile
+    qt, kt, vt = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
+    out, lse = _call(kernel, grid, qt, kt, vt, B, Sq, H, D, Dv, pad_q,
+                     block_q, block_kv, g, interpret)
+    return jnp.swapaxes(out[:, :, :Sq], 1, 2), lse[:, :, :Sq, 0]
 
 
 def _call(kernel, grid, q, k, v, B, Sq, H, D, Dv, pad_q, block_q, block_kv, g, interpret):
@@ -150,21 +159,23 @@ def _call(kernel, grid, q, k, v, B, Sq, H, D, Dv, pad_q, block_q, block_kv, g, i
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, iq, ik: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, block_kv, 1, Dv), lambda b, h, iq, ik: (b, ik, h // g, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, block_kv, Dv), lambda b, h, iq, ik: (b, h // g, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, 1, Dv), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik: (b, h, iq, 0)),
+            # lse as a trailing unit column: its tile is (block_q, 1), whose
+            # last dim equals the array's — a legal TPU block
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sq + pad_q, H, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq + pad_q), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq + pad_q, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq + pad_q, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         interpret=interpret,

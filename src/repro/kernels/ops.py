@@ -12,6 +12,7 @@ The dry-run always takes the ref path so XLA cost analysis sees the real math.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
@@ -41,7 +42,7 @@ def _interpret() -> bool:
 # -- rmsnorm ---------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _fused_rmsnorm(x: jax.Array, gamma: jax.Array, eps: float) -> jax.Array:
-    """Pallas rmsnorm on the training hot path (``--fused-rmsnorm``).
+    """Pallas rmsnorm: ``--fused-rmsnorm``, and every aligned norm on TPU.
 
     The forward pass is the kernel (interpret mode off TPU — it handles
     unaligned feature dims, so the %128 tile gate below does not apply);
@@ -68,12 +69,10 @@ _fused_rmsnorm.defvjp(_fused_rmsnorm_fwd, _fused_rmsnorm_bwd)
 
 def rmsnorm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6,
             fused: bool = False) -> jax.Array:
-    if fused and _FORCE != "ref":
+    # both kernel routes share the custom VJP: pallas_call has no JVP rule,
+    # so a bare kernel call would break jax.grad of every train step
+    if (fused and _FORCE != "ref") or (_use_pallas() and x.shape[-1] % 128 == 0):
         return _fused_rmsnorm(x, gamma, float(eps))
-    if _use_pallas() and x.shape[-1] % 128 == 0:
-        from .rmsnorm import rmsnorm_pallas
-
-        return rmsnorm_pallas(x, gamma, eps=eps, interpret=_interpret())
     with jax.named_scope("kernel_rmsnorm"):
         return ref.rmsnorm(x, gamma, eps)
 
@@ -135,7 +134,8 @@ def attention(
 # -- selective scan -------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _fused_ssm(x, dt, A, Bc, Cc, D, chunk: int):
-    """Pallas selective scan on the training hot path (``--fused-ssm``).
+    """Pallas selective scan: ``--fused-ssm``, and every aligned fresh-state
+    scan on TPU.
 
     Forward is the chunked Pallas kernel (interpret mode off TPU; it pads L
     internally and ``block_d`` is snapped to a divisor of the channel dim so
@@ -143,13 +143,11 @@ def _fused_ssm(x, dt, A, Bc, Cc, D, chunk: int):
     w.r.t. the same math.  Fresh-state only (h0=None): the decode/resume
     paths keep the ref oracle.
     """
-    import math as _math
-
     from .ssm_scan import ssm_scan_pallas
 
     return ssm_scan_pallas(
         x, dt, A, Bc, Cc, D, h0=None, chunk=chunk,
-        block_d=_math.gcd(x.shape[-1], 512), interpret=_interpret())
+        block_d=math.gcd(x.shape[-1], 512), interpret=_interpret())
 
 
 def _fused_ssm_fwd(x, dt, A, Bc, Cc, D, chunk):
@@ -169,9 +167,13 @@ def ssm_scan(x, dt, A, Bc, Cc, D, h0=None, chunk: int = 128, fused: bool = False
     if fused and _FORCE != "ref" and h0 is None:
         return _fused_ssm(x, dt, A, Bc, Cc, D, chunk)
     if _use_pallas() and L % chunk == 0 and x.shape[-1] % 128 == 0:
+        if h0 is None:  # the custom VJP keeps this path differentiable
+            return _fused_ssm(x, dt, A, Bc, Cc, D, chunk)
         from .ssm_scan import ssm_scan_pallas
 
-        return ssm_scan_pallas(x, dt, A, Bc, Cc, D, h0=h0, chunk=chunk, interpret=_interpret())
+        return ssm_scan_pallas(x, dt, A, Bc, Cc, D, h0=h0, chunk=chunk,
+                               block_d=math.gcd(x.shape[-1], 512),
+                               interpret=_interpret())
     with jax.named_scope("kernel_ssm_scan"):
         return ref.ssm_scan(x, dt, A, Bc, Cc, D, h0=h0, chunk=chunk)
 

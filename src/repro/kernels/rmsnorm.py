@@ -19,7 +19,7 @@ from jax.experimental import pallas as pl
 def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
-    scale = jax.lax.rsqrt(var + eps) * (1.0 + g_ref[...].astype(jnp.float32))[None, :]
+    scale = jax.lax.rsqrt(var + eps) * (1.0 + g_ref[...].astype(jnp.float32))
     o_ref[...] = (x * scale).astype(o_ref.dtype)
 
 
@@ -49,12 +49,15 @@ def rmsnorm_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            # gamma as a (1, d) row: its tile stays legal when vmap adds a
+            # leading lane axis (a 1-D block would put the squeezed lane
+            # dim in the second-minor position)
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
-    )(x2, gamma)
+    )(x2, gamma.reshape(1, d))
     if pad:
         out = out[:rows]
     return out.reshape(orig_shape)

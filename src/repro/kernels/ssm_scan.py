@@ -2,7 +2,7 @@
 
 TPU-native adaptation of the Mamba CUDA scan: instead of a warp-level
 parallel scan, the sequence is cut into VMEM-sized chunks and the grid's last
-dimension sweeps chunks **sequentially on-core**, carrying the (D_blk, N) SSM
+dimension sweeps chunks **sequentially on-core**, carrying the (N, D_blk) SSM
 state in VMEM scratch — the TPU analogue of keeping the recurrence in
 registers/SMEM.  Within a chunk the recurrence runs as a fori_loop of rank-1
 state updates, fully vectorized over the channel block on the VPU:
@@ -10,8 +10,8 @@ state updates, fully vectorized over the channel block on the VPU:
     h[t] = exp(dt[t] * A) * h[t-1] + (dt[t] * x[t]) ⊗ B[t]
     y[t] = h[t] · C[t] + D * x[t]
 
-grid = (batch, D/block_d, L/chunk); block spec tiles:
-    x, dt  (chunk, block_d)   B, C  (chunk, N)   A (block_d, N)   D (block_d,)
+grid = (batch, D/block_d, L/chunk); block spec tiles (N on the sublanes):
+    x, dt  (chunk, block_d)   B, C  (N, chunk)   A (N, block_d)   D (1, block_d)
 
 The channel dim is blocked (block_d) so falcon-mamba's d_inner=8192 chunk
 tiles stay ~4 MiB; N=16 keeps the state tiny.  fp32 state throughout.
@@ -33,33 +33,37 @@ def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
 
     @pl.when(ic == 0)
     def _init():
-        h_scr[...] = h0_ref[0, :, :].astype(jnp.float32)
+        h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0, :, :].astype(jnp.float32)     # (chunk, Dblk)
-    dt = dt_ref[0, :, :].astype(jnp.float32)   # (chunk, Dblk)
-    bc = b_ref[0, :, :].astype(jnp.float32)    # (chunk, N)
-    cc = c_ref[0, :, :].astype(jnp.float32)    # (chunk, N)
-    a = a_ref[...].astype(jnp.float32)         # (Dblk, N)
-    d = d_ref[...].astype(jnp.float32)         # (Dblk,)
+    # state-major layout: h is (N, Dblk), so a token's channel row (1, Dblk)
+    # broadcasts down the N sublanes and its (N,) B/C coefficients are
+    # (N, 1) columns picked out of the chunk's (N, chunk) tiles by a lane
+    # mask — every in-loop access is a ref row or a full-tile op, never a
+    # dynamic slice of a loaded value (which Mosaic cannot lower)
+    bt = b_ref[0].astype(jnp.float32)          # (N, chunk)
+    ct = c_ref[0].astype(jnp.float32)          # (N, chunk)
+    at = a_ref[...].astype(jnp.float32)        # (N, Dblk)
+    d = d_ref[...].astype(jnp.float32)         # (1, Dblk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, bt.shape, 1)
 
-    def step(t, carry):
-        h, y = carry
-        dA = jnp.exp(dt[t][:, None] * a)                     # (Dblk, N)
-        dBx = (dt[t] * x[t])[:, None] * bc[t][None, :]       # (Dblk, N)
-        h = h * dA + dBx
-        yt = h @ cc[t] + d * x[t]                            # (Dblk,)
-        y = jax.lax.dynamic_update_index_in_dim(y, yt, t, 0)
-        return h, y
+    def step(t, h):
+        xt = x_ref[0, pl.ds(t, 1), :].astype(jnp.float32)    # (1, Dblk)
+        dtt = dt_ref[0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, Dblk)
+        pick = lane == t
+        b_col = jnp.sum(jnp.where(pick, bt, 0.0), axis=1, keepdims=True)  # (N, 1)
+        c_col = jnp.sum(jnp.where(pick, ct, 0.0), axis=1, keepdims=True)  # (N, 1)
+        h = h * jnp.exp(dtt * at) + b_col * (dtt * xt)       # (N, Dblk)
+        yt = jnp.sum(h * c_col, axis=0, keepdims=True) + d * xt
+        y_ref[0, pl.ds(t, 1), :] = yt.astype(y_ref.dtype)
+        return h
 
-    h0 = h_scr[...]
-    y0 = jnp.zeros((chunk, x.shape[1]), jnp.float32)
-    h, y = jax.lax.fori_loop(0, chunk, step, (h0, y0))
+    h = jax.lax.fori_loop(0, chunk, step, h_scr[...])
     h_scr[...] = h
-    y_ref[0, :, :] = y.astype(y_ref.dtype)
 
     @pl.when(ic == nc - 1)
     def _finish():
-        hout_ref[0, :, :] = h
+        hout_ref[0] = h
+
 
 @functools.partial(
     jax.jit, static_argnames=("chunk", "block_d", "interpret")
@@ -95,27 +99,31 @@ def ssm_scan_pallas(
 
     grid = (B, nd, nc)
     kernel = functools.partial(_ssm_kernel, chunk=chunk, nc=nc)
+    # the kernel keeps N on the sublanes: B/C, A, the state and D are handed
+    # over transposed (tiny next to x/dt) so every block's last two dims are
+    # (8k, 128k)-tileable or whole
+    bt, ct = jnp.swapaxes(Bc, 1, 2), jnp.swapaxes(Cc, 1, 2)
     y, h_last = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b, j, ic: (b, ic, j)),
             pl.BlockSpec((1, chunk, block_d), lambda b, j, ic: (b, ic, j)),
-            pl.BlockSpec((1, chunk, N), lambda b, j, ic: (b, ic, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, j, ic: (b, ic, 0)),
-            pl.BlockSpec((block_d, N), lambda b, j, ic: (j, 0)),
-            pl.BlockSpec((block_d,), lambda b, j, ic: (j,)),
-            pl.BlockSpec((1, block_d, N), lambda b, j, ic: (b, j, 0)),
+            pl.BlockSpec((1, N, chunk), lambda b, j, ic: (b, 0, ic)),
+            pl.BlockSpec((1, N, chunk), lambda b, j, ic: (b, 0, ic)),
+            pl.BlockSpec((N, block_d), lambda b, j, ic: (0, j)),
+            pl.BlockSpec((1, block_d), lambda b, j, ic: (0, j)),
+            pl.BlockSpec((1, N, block_d), lambda b, j, ic: (b, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b, j, ic: (b, ic, j)),
-            pl.BlockSpec((1, block_d, N), lambda b, j, ic: (b, j, 0)),
+            pl.BlockSpec((1, N, block_d), lambda b, j, ic: (b, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Lp, Dm), x.dtype),
-            jax.ShapeDtypeStruct((B, Dm, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, N, Dm), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, block_d), jnp.float32)],
         interpret=interpret,
-    )(x, dt, Bc, Cc, A, D, h0)
-    return y[:, :L], h_last
+    )(x, dt, bt, ct, A.T, D.reshape(1, Dm), jnp.swapaxes(h0, 1, 2))
+    return y[:, :L], jnp.swapaxes(h_last, 1, 2)

@@ -116,7 +116,7 @@ def lower_cell(
 ) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from ..configs import get_config, memory_policy
     from ..configs.base import SHAPES, TrainConfig
@@ -147,7 +147,8 @@ def lower_cell(
         n = 512 if multi_pod else 256
         shp = (2, (n // 2) // tp, tp) if multi_pod else (n // tp, tp)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        mesh = jax.make_mesh(shp, axes, devices=jax.devices()[:n])
+        mesh = jax.make_mesh(shp, axes, devices=jax.devices()[:n],
+                             axis_types=(AxisType.Auto,) * len(axes))
         pc = _dc.replace(pc, mesh_shape=shp, mesh_axes=axes)
     n_chips = mesh.size
     rules = make_rules(pc.mesh_axes, shard_cache_seq=pc.shard_cache_seq)

@@ -458,6 +458,7 @@ class PopulationTrial:
             get_compiled_sharded_population_step,
             init_population_state,
             init_population_state_from_keys,
+            init_population_state_on_mesh,
             pad_population,
             population_scores,
             shard_population_state,
@@ -492,6 +493,9 @@ class PopulationTrial:
         if self.per_trial_init:
             keys = jnp.stack([self._init_key(s) for s in streams])
             pstate = init_population_state_from_keys(keys, tc)
+        elif mesh is not None and not elastic_on:
+            pstate = init_population_state_on_mesh(
+                jax.random.PRNGKey(self.seed), tc, k, mesh)
         else:
             pstate = init_population_state(jax.random.PRNGKey(self.seed), tc, k)
         if elastic_on:
@@ -1825,6 +1829,9 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     from ..core import faultinject
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from ..core.experiment import Experiment
     from ..core.tracking.database import TrackingDB
 

@@ -11,12 +11,16 @@ Topology (TPU v5e target):
 
 ``model`` is the high-bandwidth inner axis (TP/EP); ``data``/(``pod``,``data``)
 carry batch + FSDP.  ``make_slice_mesh`` builds sub-meshes for HPO trials.
+Every axis is ``AxisType.Auto``: the sharding rules here speak GSPMD
+(``with_sharding_constraint``), which Explicit axes — ``jax.make_mesh``'s
+default — reject.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -31,7 +35,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"production mesh {shape} needs {n} devices, found {len(devices)}; "
             "run under launch/dryrun.py which forces 512 host devices"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_trial_mesh(
@@ -51,4 +56,5 @@ def make_trial_mesh(
         while n_devices % d:
             d -= 1
         shape = (d, n_devices // d)
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -92,6 +92,9 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     from ..checkpoint.checkpointer import Checkpointer
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     tc, mesh, data, jitted, state_sh = build(args)
 

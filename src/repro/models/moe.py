@@ -76,7 +76,7 @@ def _moe_shard_map(p: Params, x: jax.Array, cfg: ModelConfig, mesh, rules):
     avoids XLA's SPMD partitioner turning the dispatch scatter/gather into
     mesh-wide partial-gather + all-reduce (measured 25x worse).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = tuple(a for a in rules.get("batch", ()) if a in mesh.axis_names)
@@ -150,7 +150,7 @@ def _moe_shard_map(p: Params, x: jax.Array, cfg: ModelConfig, mesh, rules):
             P(mp, None, None),
         ),
         out_specs=(P(dp_spec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     y, aux = fn(x, p["router"], p["wi"], p["wo"])
     if cfg.n_shared_experts:

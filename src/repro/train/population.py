@@ -97,7 +97,10 @@ def init_population_state(key, tc: TrainConfig, population: int) -> PopState:
     trial with the same seed); only their traced hyperparameters differ.
     Use ``init_population_state_from_keys`` for per-trial init seeds.
     """
-    one = init_train_state(key, tc)
+    return _broadcast_lanes(init_train_state(key, tc), population)
+
+
+def _broadcast_lanes(one, population: int) -> PopState:
     inner = jax.tree.map(lambda x: jnp.broadcast_to(x, (population,) + x.shape), one)
     return _wrap(inner, population)
 
@@ -192,11 +195,16 @@ def _tp_state_pspecs(tc: TrainConfig, mesh: Mesh, axis: str,
 
 def _fused_kernels_on(tc: TrainConfig) -> bool:
     """shard_map's static replication checker has no rule for pallas_call, so
-    the width-1 sharded twins must drop to ``check_rep=False`` whenever a
-    fused Pallas kernel rides inside the train step (the width>1 twins always
-    do: the checker cannot see through the custom_vjp psum seams either)."""
+    the width-1 sharded twins must drop to ``check_vma=False`` whenever a
+    Pallas kernel rides inside the train step: a ``--fused-*`` flag, or any
+    backend on which ``kernels.ops`` routes aligned shapes to its kernels
+    (the TPU).  The width>1 twins always do: the checker cannot see through
+    the custom_vjp psum seams either."""
+    from ..kernels import ops
+
     m = tc.model
-    return bool(m.fused_rmsnorm or m.fused_attention or m.fused_ssm)
+    return bool(m.fused_rmsnorm or m.fused_attention or m.fused_ssm
+                or ops._use_pallas())
 
 
 def _tp_body(fn: Callable, tc: TrainConfig, width: int,
@@ -477,11 +485,14 @@ def place_two_level(pstate: PopState, tc: TrainConfig, mesh: Mesh,
     partitioning the tensor-parallel step computes on — so a regrid onto a
     wider mesh genuinely re-partitions survivor state (optimizer memory per
     device drops ~1/W) instead of replicating it."""
+    return jax.device_put(pstate, _two_level_layout(pstate, tc, mesh, axis))
+
+
+def _two_level_layout(pstate, tc: TrainConfig, mesh: Mesh, axis: str):
     width = _mesh_width(mesh, axis)
     rules = tp_width_rules(tc.model, width) if width > 1 else None
-    return jax.device_put(
-        pstate, two_level_state_specs(
-            pstate, _state_logical_specs(tc), mesh, axis=axis, rules=rules))
+    return two_level_state_specs(
+        pstate, _state_logical_specs(tc), mesh, axis=axis, rules=rules)
 
 
 def regrid_population_state(
@@ -515,7 +526,7 @@ def regrid_population_state(
 def make_sharded_lane_init(tc: TrainConfig, mesh: Mesh, axis: str = "pop") -> Callable:
     """Lane reset with the K axis split over ``mesh`` (mirrors the sharded
     population step): each device re-inits only its own K/N block of lanes."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     reset = make_lane_init(tc)
     pop = PartitionSpec(axis)
@@ -539,7 +550,7 @@ def make_sharded_lane_clone(tc: TrainConfig, mesh: Mesh, axis: str = "pop") -> C
     wire traffic is the same N-1 blocks the gather moved, and the copied
     values are bit-identical to the vmapped clone's.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = int(mesh.shape[axis])
 
@@ -580,7 +591,7 @@ def make_sharded_lane_splice(tc: TrainConfig, mesh: Mesh, axis: str = "pop") -> 
     init but only the owner of the target lane writes it into its local
     block — the rest keep their block bit-identical.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def splice(pstate: PopState, lane: jax.Array, key: jax.Array) -> PopState:
         blk = pstate["diverged"].shape[0]  # local lanes per device
@@ -625,7 +636,7 @@ def make_sharded_lane_snapshot(tc: TrainConfig, mesh: Mesh, axis: str = "pop") -
     leaves ride the sum as int32 (a masked sum of one contribution, so the
     round-trip is exact).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def snapshot(pstate: PopState, lane: jax.Array):
         blk = pstate["diverged"].shape[0]  # local lanes per device
@@ -663,7 +674,7 @@ def make_sharded_lane_restore(tc: TrainConfig, mesh: Mesh, axis: str = "pop") ->
     target lane writes the snapshot into its local block (mirrors the sharded
     splice), so the other devices' blocks stay bit-identical.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def restore(pstate: PopState, lane: jax.Array, snap) -> PopState:
         blk = pstate["diverged"].shape[0]
@@ -760,7 +771,7 @@ def make_sharded_population_scan_step(
     ``make_sharded_population_step``); the in-scan batch synthesis replicates
     across the row (same lanes, same streams), which is exactly the TP batch
     contract."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     fn = make_population_scan_step(
         tc, data, chunk, per_trial_batch=per_trial_batch)
@@ -775,14 +786,14 @@ def make_sharded_population_scan_step(
             mesh=mesh,
             in_specs=(state_ps, pop, lane, lane, lane),
             out_specs=(state_ps, PartitionSpec(None, axis)),
-            check_rep=False,
+            check_vma=False,
         )
     return shard_map(
         fn,
         mesh=mesh,
         in_specs=(pop, pop, lane, lane, lane),
         out_specs=(pop, PartitionSpec(None, axis)),
-        check_rep=not _fused_kernels_on(tc),
+        check_vma=not _fused_kernels_on(tc),
     )
 
 
@@ -834,7 +845,7 @@ def make_sharded_population_ring_scan_step(
     the ``pop`` mesh axis, so each device scans over its own K/N lane block
     reading only its own lanes' slabs (the host fill ``device_put``s slabs
     with the same sharding — no gather)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     fn = make_population_ring_scan_step(tc, data, chunk, capacity)
     pop = PartitionSpec(axis)
@@ -846,14 +857,14 @@ def make_sharded_population_ring_scan_step(
             mesh=mesh,
             in_specs=(state_ps, pop, PartitionSpec(None, axis), PartitionSpec()),
             out_specs=(state_ps, PartitionSpec(None, axis)),
-            check_rep=False,
+            check_vma=False,
         )
     return shard_map(
         fn,
         mesh=mesh,
         in_specs=(pop, pop, PartitionSpec(None, axis), PartitionSpec()),
         out_specs=(pop, PartitionSpec(None, axis)),
-        check_rep=not _fused_kernels_on(tc),
+        check_vma=not _fused_kernels_on(tc),
     )
 
 
@@ -876,7 +887,7 @@ def make_sharded_population_step(
     channels width-local per ``tp_width_rules``, psums at the model-code
     seams), so the model axis carries compute instead of replicas.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     step = make_population_train_step(tc, per_trial_batch=per_trial_batch)
     pop = PartitionSpec(axis)
@@ -884,7 +895,7 @@ def make_sharded_population_step(
     width = _mesh_width(mesh, axis)
     if width > 1:
         state_ps, _ = _tp_state_pspecs(tc, mesh, axis)
-        # check_rep=False: activations/metrics ARE replicated across each lane
+        # check_vma=False: activations/metrics ARE replicated across each lane
         # row (the seam psums make them so), but the static replication
         # checker cannot see through custom_vjp seams
         return shard_map(
@@ -892,14 +903,14 @@ def make_sharded_population_step(
             mesh=mesh,
             in_specs=(state_ps, batch_spec, pop),
             out_specs=(state_ps, pop),
-            check_rep=False,
+            check_vma=False,
         )
     return shard_map(
         step,
         mesh=mesh,
         in_specs=(pop, batch_spec, pop),
         out_specs=(pop, pop),
-        check_rep=not _fused_kernels_on(tc),
+        check_vma=not _fused_kernels_on(tc),
     )
 
 
@@ -1223,7 +1234,7 @@ def make_sharded_population_rule_scan_step(
     slices its own block of the new budgets back out — so the sharded cut
     set is bit-identical to the vmapped engine's by construction.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     fn = make_population_rule_scan_step(
         tc, data, chunk, mode, per_trial_batch=per_trial_batch,
@@ -1233,7 +1244,7 @@ def make_sharded_population_rule_scan_step(
     rep = PartitionSpec()
     lane = pop if per_trial_batch else rep
     rules_spec = rule_state_specs(mode, axis)
-    # check_rep=False: the history/window leaves ARE replicated (every device
+    # check_vma=False: the history/window leaves ARE replicated (every device
     # runs the identical global update on all_gather-ed inputs), but the
     # static replication checker cannot infer that through the gather
     width = _mesh_width(mesh, axis)
@@ -1248,14 +1259,14 @@ def make_sharded_population_rule_scan_step(
             mesh=mesh,
             in_specs=(state_ps, pop, lane, lane, lane, rules_spec),
             out_specs=((state_ps, rules_spec), PartitionSpec(None, axis)),
-            check_rep=False,
+            check_vma=False,
         )
     return shard_map(
         fn,
         mesh=mesh,
         in_specs=(pop, pop, lane, lane, lane, rules_spec),
         out_specs=((pop, rules_spec), PartitionSpec(None, axis)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -1267,6 +1278,16 @@ def pad_population(k: int, mesh: Optional[Mesh], axis: str = "pop") -> int:
     return ((max(k, 1) + n - 1) // n) * n
 
 
+def _population_layout(pstate, mesh: Mesh, axis: str,
+                       tc: Optional[TrainConfig]):
+    """Per-leaf shardings of a population state (or its shapes) on ``mesh``:
+    lanes over ``axis``; on a two-level mesh with ``tc``, each lane's
+    leaves width-partitioned per ``tp_width_rules``."""
+    if tc is not None and _mesh_width(mesh, axis) > 1:
+        return _two_level_layout(pstate, tc, mesh, axis)
+    return population_specs(pstate, mesh, axis)
+
+
 def shard_population_state(
     pstate: PopState, mesh: Mesh, axis: str = "pop",
     tc: Optional[TrainConfig] = None,
@@ -1276,9 +1297,24 @@ def shard_population_state(
     On a two-level mesh pass ``tc`` so each lane's parameter/optimizer leaves
     land width-partitioned per ``tp_width_rules`` (matching what the TP step
     computes on) instead of row-replicated."""
-    if tc is not None and _mesh_width(mesh, axis) > 1:
-        return place_two_level(pstate, tc, mesh, axis=axis)
-    return jax.device_put(pstate, population_specs(pstate, mesh, axis))
+    return jax.device_put(pstate, _population_layout(pstate, mesh, axis, tc))
+
+
+def init_population_state_on_mesh(
+    key, tc: TrainConfig, population: int, mesh: Mesh, axis: str = "pop",
+) -> PopState:
+    """``init_population_state`` laid straight onto ``mesh``, in the layout
+    ``shard_population_state(..., tc=tc)`` gives.
+
+    The one template lane is initialized as on a single device (the same
+    numerics); the K-fold broadcast runs under ``jit`` with the population
+    layout as ``out_shardings``, so every device materializes only its own
+    lanes.  Broadcasting on one device first would hold the whole
+    population there — at published widths, more than one chip's memory."""
+    out = _population_layout(
+        _population_state_shapes(tc, population), mesh, axis, tc)
+    return jax.jit(lambda one: _broadcast_lanes(one, population),
+                   out_shardings=out)(init_train_state(key, tc))
 
 
 # -- compile-once caches --------------------------------------------------------

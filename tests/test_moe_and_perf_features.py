@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import get_smoke_config
 from repro.configs.base import ParallelConfig, TrainConfig
@@ -26,7 +27,7 @@ def _setup(arch="qwen3-moe-30b-a3b", capacity=100.0):
 def test_shard_map_moe_matches_plain():
     cfg, p, x = _setup()
     y0, a0 = M.moe_apply(p, x, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     with sharding_context(mesh, make_rules(("data", "model"))):
         y1, a1 = jax.jit(lambda p, x: M.moe_apply(p, x, cfg))(p, x)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), atol=3e-5)
@@ -36,7 +37,7 @@ def test_shard_map_moe_matches_plain():
 def test_shard_map_moe_grads_match_plain():
     cfg, p, x = _setup()
     g0 = jax.grad(lambda x: (M.moe_apply(p, x, cfg)[0] ** 2).sum())(x)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     with sharding_context(mesh, make_rules(("data", "model"))):
         g1 = jax.jit(jax.grad(lambda x: (M.moe_apply(p, x, cfg)[0] ** 2).sum()))(x)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g0), atol=3e-4, rtol=3e-4)
@@ -63,7 +64,7 @@ def test_dropless_ignores_groups_and_ctx():
     """Decode path (dropless) must stay exact regardless of grouping/ctx."""
     cfg, p, x = _setup()
     y0, _ = M.moe_apply(p, x, cfg, dropless=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     with sharding_context(mesh, make_rules(("data", "model"))):
         y1, _ = jax.jit(lambda: M.moe_apply(
             p, x, dataclasses.replace(cfg, moe_groups=4), dropless=True))()
@@ -76,7 +77,7 @@ def test_shard_map_moe_skips_when_experts_unshardable():
     cfg6 = dataclasses.replace(cfg, n_experts=6, moe_top_k=2)
     p6 = M.moe_init(jax.random.PRNGKey(0), cfg6)
     y0, _ = M.moe_apply(p6, x, cfg6)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
     class FakeMesh:  # pretend the model axis is 4-way for the dispatch check
         axis_names = ("data", "model")
@@ -97,7 +98,7 @@ def test_zero1_vs_fsdp_sharding_trees():
         functools.partial(init_train_state, tc=tc), jax.ShapeDtypeStruct((2,), jnp.uint32)
     )
     specs = train_state_specs(tc)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     rules = make_rules(("data", "model"))
 
     fsdp = build_sharding(state_shapes, specs, rules, mesh)
@@ -128,7 +129,7 @@ def test_moe_arch_smoke_with_sharding_ctx():
         "targets": jnp.zeros((2, 16), jnp.int32),
         "mask": jnp.ones((2, 16), jnp.float32),
     }
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     rules = make_rules(("data", "model"))
     step = make_train_step(tc)
 
