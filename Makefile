@@ -6,8 +6,8 @@
 #                  faulthandler watchdog otherwise — see tests/conftest.py)
 #                  so a deadlocked streaming-flush thread fails instead of
 #                  hanging CI; tune with PYTEST_TIMEOUT=<seconds>
-#   make bench   — smoke benchmarks: HPO trial-engine throughput (emits
-#                  BENCH_hpo_throughput.json) + extensibility LOC count
+#   make bench   — smoke benchmark: extensibility LOC count (speed is
+#                  measured on the chip: BENCHMARK.json, benchmarks/chip)
 #   make bench-all — every registered benchmark (slow: full roofline sweep)
 #   make docs-check — README/docs snippets compile, imports resolve, CLI
 #                  flags and make targets referenced in docs exist
@@ -21,7 +21,7 @@ test:
 	$(PYTHON) -m pytest -x -q
 
 bench:
-	$(PYTHON) -m benchmarks.run --only hpo_throughput,extensibility
+	$(PYTHON) -m benchmarks.run --only extensibility
 
 bench-all:
 	$(PYTHON) -m benchmarks.run

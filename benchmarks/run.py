@@ -11,7 +11,7 @@ import os
 import sys
 import time
 
-BENCHES = ("table1", "fig3", "fig4", "fig5", "extensibility", "hpo_throughput", "roofline")
+BENCHES = ("table1", "fig3", "fig4", "fig5", "extensibility", "roofline")
 OUT_DIR = "artifacts/bench"
 
 
@@ -30,9 +30,6 @@ def _run_one(name: str):
         return m.run()
     if name == "extensibility":
         from . import extensibility_loc as m
-        return m.run()
-    if name == "hpo_throughput":
-        from . import hpo_throughput as m
         return m.run()
     if name == "roofline":
         from . import roofline as m

@@ -32,7 +32,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-from . import faultinject
+from . import faultinject, tracing
 from .basic_config import BasicConfig
 from .job import Job, JobStatus
 from .proposer import make_proposer
@@ -186,7 +186,9 @@ class Experiment:
         while self._requeue and len(out) < k:
             out.append(self._requeue.pop(0))
         if len(out) < k and not self.proposer.finished():
-            out.extend((cfg, 0) for cfg in self.proposer.get_params(k - len(out)))
+            with tracing.span("experiment.propose", n=k - len(out)):
+                cfgs = self.proposer.get_params(k - len(out))
+            out.extend((cfg, 0) for cfg in cfgs)
         return out
 
     def _drain_finished_locked(self) -> None:
@@ -206,7 +208,8 @@ class Experiment:
             if job.status != JobStatus.LOST:
                 self.rm.release(job.resource_id)
             if ok:
-                self.proposer.update(res.score, job)
+                with tracing.span("experiment.settle", job_id=job.job_id):
+                    self.proposer.update(res.score, job)
                 self._fire_result_callbacks(job)
             else:
                 # per-job retry counter rides on the Job itself: distinct
@@ -224,7 +227,8 @@ class Experiment:
                 else:
                     # quarantined jobs (poison lane across consecutive flight
                     # deaths) skip their remaining retry budget by design
-                    self.proposer.update(None, job)
+                    with tracing.span("experiment.settle", job_id=job.job_id):
+                        self.proposer.update(None, job)
                     self._fire_result_callbacks(job)
 
     def _fire_result_callbacks(self, job: Job) -> None:

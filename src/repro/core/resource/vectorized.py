@@ -61,11 +61,13 @@ from __future__ import annotations
 
 import inspect
 import threading
+import time
 import warnings
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from . import ResourceManager, register
+from .. import tracing
 from ..job import Job, JobResult, JobStatus
 
 
@@ -119,6 +121,8 @@ class LaneScheduler:
         self.n_donor_waits = 0
         self.n_device_retired = 0  # retirements harvested from a scan's log
         self._donor_waited: set = set()  # job ids counted once, not per poll
+        # job id -> its open trial.queued span (offer -> lease), while tracing
+        self._queued: Dict[int, tracing.Begun] = {}
 
     # -- manager side -----------------------------------------------------------
     def offer(self, job: Job) -> bool:
@@ -128,6 +132,9 @@ class LaneScheduler:
             if self.closed:
                 return False
             self._queue.append(job)
+            queued = tracing.begin("trial.queued")
+            if queued is not None:
+                self._queued[job.job_id] = queued
             return True
 
     def close(self) -> Tuple[List[Job], List[Job]]:
@@ -136,6 +143,7 @@ class LaneScheduler:
             self.closed = True
             leftovers = [j for j in self._queue if j.status == JobStatus.PENDING]
             self._queue.clear()
+            self._queued.clear()
             orphans = list(self._live.values())
             self._live.clear()
         return leftovers, orphans
@@ -167,6 +175,11 @@ class LaneScheduler:
                 self._live[handle] = job
                 self.n_leased += 1
                 job.mark_running()
+                queued = self._queued.pop(job.job_id, None) \
+                    if self._queued else None
+                if queued is not None:
+                    tracing.record("trial.queued", queued, time.time(),
+                                   job_id=job.job_id)
                 return handle, dict(job.config)
             return None
 

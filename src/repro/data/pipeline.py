@@ -34,6 +34,8 @@ from typing import Any, Dict, Protocol, Sequence, Tuple
 
 import numpy as np
 
+from ..core import tracing
+
 _U64 = 0xFFFFFFFFFFFFFFFF
 _U32 = 0xFFFFFFFF
 
@@ -244,9 +246,10 @@ class SyntheticLM:
         steps = [int(step)] * len(streams) if np.isscalar(step) \
             else [int(s) for s in step]
         assert len(steps) == len(streams)
-        lo, hi = split_streams(streams)
-        return synth_population_batch(
-            self, lo, hi, np.asarray(steps, np.int64), xp=np)
+        with tracing.span("data.host_batch", lanes=len(streams)):
+            lo, hi = split_streams(streams)
+            return synth_population_batch(
+                self, lo, hi, np.asarray(steps, np.int64), xp=np)
 
 
 class HostDataset(Protocol):

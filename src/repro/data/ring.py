@@ -21,8 +21,8 @@ Fence protocol (single lock + condition, two monotone step pointers):
   never overwritten.
 
 ``wait_filled(s, want)`` blocks until steps ``[s, s + want)`` are filled
-(accumulating ``fill_wait_s`` — the time device work actually waited on the
-host).  The driver asks for exactly the ``ChunkPlanner.chunk_to`` horizon it
+(accumulating ``fill_wait_s`` — the host time the driver blocked on the
+fill).  The driver asks for exactly the ``ChunkPlanner.chunk_to`` horizon it
 is about to dispatch, so chunk horizons stay capped to filled windows while
 the dispatch sequence remains bit-identical to the in-scan-synth engine —
 a lagging fill costs wait time, never a different chunk split (which would
@@ -53,6 +53,8 @@ import time
 from typing import Optional, Sequence
 
 import numpy as np
+
+from ..core import tracing
 
 
 _RING_WRITE = None
@@ -162,7 +164,7 @@ class PrefetchRing:
         a stateful proposer."""
         want = max(1, min(int(want), self.capacity))
         t0 = None
-        with self._lock:
+        with tracing.span("ring.wait"), self._lock:
             while self._filled_to < s + want and self._error is None \
                     and not self._stopped:
                 if t0 is None:
@@ -235,20 +237,21 @@ class PrefetchRing:
                     n = min(self.win_steps, self.capacity - slot0, free)
 
                 t0 = time.perf_counter()
-                window = getattr(self.dataset, "lane_window", None)
-                if window is not None:
-                    # one vectorized call per window — amortizes the
-                    # per-call synthesis overhead across all n steps
-                    block = window(streams, offsets + s0, n)
-                else:
-                    block = np.stack([
-                        self.dataset.lane_block(streams, offsets + (s0 + t))
-                        for t in range(n)
-                    ])  # (n, K, B, L+1) int32
-                dev = jax.device_put(
-                    jnp.asarray(block, jnp.int32), self._sharding) \
-                    if self._sharding is not None else jnp.asarray(
-                        block, jnp.int32)
+                with tracing.span("ring.fill", steps=n):
+                    window = getattr(self.dataset, "lane_window", None)
+                    if window is not None:
+                        # one vectorized call per window — amortizes the
+                        # per-call synthesis overhead across all n steps
+                        block = window(streams, offsets + s0, n)
+                    else:
+                        block = np.stack([
+                            self.dataset.lane_block(streams, offsets + (s0 + t))
+                            for t in range(n)
+                        ])  # (n, K, B, L+1) int32
+                    dev = jax.device_put(
+                        jnp.asarray(block, jnp.int32), self._sharding) \
+                        if self._sharding is not None else jnp.asarray(
+                            block, jnp.int32)
 
                 with self._lock:
                     if self._stopped:

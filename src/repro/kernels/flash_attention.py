@@ -158,6 +158,8 @@ def _call(kernel, grid, q, k, v, B, Sq, H, D, Dv, pad_q, block_q, block_kv, g, i
     return pl.pallas_call(
         kernel,
         grid=grid,
+        # the device trace's op name for this kernel (benchmark rules match it)
+        name="_flash_fwd_pallas",
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_kv, D), lambda b, h, iq, ik: (b, h // g, ik, 0)),

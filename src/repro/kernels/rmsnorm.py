@@ -47,6 +47,7 @@ def rmsnorm_pallas(
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=grid,
+        name="rmsnorm_pallas",
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
             # gamma as a (1, d) row: its tile stays legal when vmap adds a

@@ -106,6 +106,7 @@ def ssm_scan_pallas(
     y, h_last = pl.pallas_call(
         kernel,
         grid=grid,
+        name="ssm_scan_pallas",
         in_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b, j, ic: (b, ic, j)),
             pl.BlockSpec((1, chunk, block_d), lambda b, j, ic: (b, ic, j)),

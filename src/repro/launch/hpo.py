@@ -89,12 +89,14 @@ as a step budget and exhausted trials freeze in place.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
 
 import numpy as np
 
+from ..core import tracing
 from .chunkplan import (
     ChunkPlanner,
     device_dispatch_horizon as _device_dispatch_horizon,
@@ -109,8 +111,8 @@ def make_trial(arch: str, steps: int, batch: int, seq: int, seed: int):
     """Legacy trial callable: config dict -> score, recompiling per trial.
 
     Bakes the proposal into the TrainConfig closure, so every call pays a
-    full XLA compile — kept as the baseline ``benchmarks/hpo_throughput.py``
-    measures against.  Use ``PopulationTrial`` for real runs.
+    full XLA compile — kept for ``--legacy-recompile`` and the tests that
+    compare against it.  Use ``PopulationTrial`` for real runs.
     """
 
     def trial(config: dict) -> float:
@@ -234,7 +236,7 @@ class PopulationTrial:
         self.data_ring = bool(data_ring)
         self.ring_windows = max(2, int(ring_windows))
         self.host_dataset = None    # HostDataset override (default: synth)
-        self.ring_fill_wait_s = 0.0   # device time spent waiting on host fill
+        self.ring_fill_wait_s = 0.0   # host time the driver blocked on the fill
         self.ring_fill_busy_s = 0.0   # host time spent producing windows
         self.ring_overlap_frac = 1.0  # fraction of fill hidden behind compute
         self.n_ring_fills = 0
@@ -262,6 +264,11 @@ class PopulationTrial:
         self.lane_width_history: list = []  # [lanes, devices-per-lane] per regrid
         self.n_dispatches = 0       # device calls issued (steps + lane ops)
         self.n_train_steps = 0      # population steps those calls advanced
+        # lane counters: lane-steps dispatched (steps x K) and those of lanes
+        # holding a trial with budget left when the dispatch was issued;
+        # their ratio is the share of device lane-steps that train a trial
+        self.n_lane_steps = 0
+        self.n_live_lane_steps = 0
         # lane-lifecycle hook (streaming PBT): maps retire->refill directives
         # (keep / clone / init) onto compiled lane ops; wired by the
         # Experiment from the proposer's lifecycle_hook()
@@ -335,6 +342,19 @@ class PopulationTrial:
 
     def _n_steps(self, config: dict) -> int:
         return int(config.get("n_iterations", 1) * self.steps)
+
+    @contextlib.contextmanager
+    def _train_dispatch(self, program: str, t: int, k: int, live: int):
+        """The enqueue of one training program of ``t`` steps over ``k``
+        lanes, ``live`` of them holding a trial with budget left: a
+        ``flight.dispatch`` span around it, then the counters."""
+        with tracing.span("flight.dispatch", steps=t, lanes=k, live=live,
+                          program=program):
+            yield
+        self.n_dispatches += 1
+        self.n_train_steps += t
+        self.n_lane_steps += t * k
+        self.n_live_lane_steps += t * live
 
     def _stream_of(self, config: dict, fallback: int) -> int:
         """Per-trial data stream id: explicit ``stream`` key, else the job id
@@ -563,8 +583,10 @@ class PopulationTrial:
                     # (counted as ring_fill_wait_s), so the dispatch sequence
                     # is identical to the in-scan engine's
                     ring.wait_filled(s, t)
+                live = int((budgets > s).sum())
                 if t > 1 and ring is not None:
-                    with ring.reserve() as slots:
+                    with ring.reserve() as slots, self._train_dispatch(
+                            "ring_scan", t, k, live):
                         pstate, _ = ring_scan_of(t)(
                             pstate, php, slots,
                             jnp.asarray(s % ring.capacity, jnp.int32))
@@ -572,25 +594,24 @@ class PopulationTrial:
                     steps0 = (jnp.full((k,), s, jnp.int32)
                               if self.per_trial_streams
                               else jnp.asarray(s, jnp.int32))
-                    pstate, _ = scan_of(t)(pstate, php, steps0, s_lo, s_hi)
+                    with self._train_dispatch("scan", t, k, live):
+                        pstate, _ = scan_of(t)(pstate, php, steps0, s_lo, s_hi)
                 else:
                     if self.per_trial_streams:
                         batch = data.make_population_batch(s, streams)
                     else:
                         batch = data.make_batch(s)
-                    pstate, _ = pstep(pstate, batch, php)
-                self.n_dispatches += 1
-                self.n_train_steps += t
+                    with self._train_dispatch("step", t, k, live):
+                        pstate, _ = pstep(pstate, batch, php)
                 s += t
                 if ring is not None:
                     ring.consume_to(s)
                 if hook is not None and s in hook.boundaries:
-                    new_budgets = hook(
-                        s,
-                        np.asarray(pstate["last_loss"]),
-                        budgets,
-                        np.asarray(pstate["diverged"]),
-                    )
+                    with tracing.span("flight.sync", step=s):
+                        last = np.asarray(pstate["last_loss"])
+                        diverged = np.asarray(pstate["diverged"])
+                    with tracing.span("flight.rung_rule", step=s):
+                        new_budgets = hook(s, last, budgets, diverged)
                     # the last_loss pull above synced the device, so this
                     # segment's wall-clock is honest: per-step time between
                     # consecutive rung boundaries
@@ -658,10 +679,11 @@ class PopulationTrial:
             fn = get_compiled_population_rule_scan_step(
                 tc, k, data, t, "cohort", mesh=mesh,
                 per_trial_batch=self.per_trial_streams)
-            (pstate, rout), _ = fn(pstate, php, steps0, s_lo, s_hi, rules)
-            budgets = np.asarray(rout["budgets"], np.float64)
-            self.n_dispatches += 1
-            self.n_train_steps += t
+            live = int((budgets > s).sum())
+            with self._train_dispatch("rule_scan", t, k, live):
+                (pstate, rout), _ = fn(pstate, php, steps0, s_lo, s_hi, rules)
+            with tracing.span("flight.sync", step=s + t):
+                budgets = np.asarray(rout["budgets"], np.float64)
             s += t
         spec.absorb_cuts(init_budgets, budgets, np.asarray(pstate["diverged"]))
         self.last_flight_steps = s
@@ -751,6 +773,7 @@ class PopulationTrial:
         while len(budgets) and s < int(budgets.max()):
             t = planner.chunk_to(s, planner.next_cohort_event(
                 s, int(budgets.max())))
+            live = int((budgets > s).sum())
             if t > 1:
                 s_lo, s_hi = splits()
                 steps0 = (jnp.full((k,), s, jnp.int32)
@@ -759,7 +782,8 @@ class PopulationTrial:
                 scan = get_compiled_population_scan_step(
                     tc, k, data, t, mesh=tp_mesh,
                     per_trial_batch=self.per_trial_streams)
-                pstate, _ = scan(pstate, php, steps0, s_lo, s_hi)
+                with self._train_dispatch("scan", t, k, live):
+                    pstate, _ = scan(pstate, php, steps0, s_lo, s_hi)
             else:
                 batch = (data.make_population_batch(s, streams)
                          if self.per_trial_streams else data.make_batch(s))
@@ -769,15 +793,17 @@ class PopulationTrial:
                          if tp_mesh is not None else
                          get_compiled_population_step(
                              tc, k, per_trial_batch=self.per_trial_streams))
-                pstate, _ = pstep(pstate, batch, php)
-            self.n_dispatches += 1
-            self.n_train_steps += t
+                with self._train_dispatch("step", t, k, live):
+                    pstate, _ = pstep(pstate, batch, php)
             s += t
             if hook is None or s not in hook.boundaries:
                 continue
-            new_budgets = np.asarray(hook(
-                s, np.asarray(pstate["last_loss"]), budgets,
-                np.asarray(pstate["diverged"])), np.float64)
+            with tracing.span("flight.sync", step=s):
+                last = np.asarray(pstate["last_loss"])
+                diverged = np.asarray(pstate["diverged"])
+            with tracing.span("flight.rung_rule", step=s):
+                new_budgets = np.asarray(
+                    hook(s, last, budgets, diverged), np.float64)
             self.per_rung_step_time_s.append(
                 [int(s), int(s - seg_s0),
                  round((time.perf_counter() - seg_t0) / max(1, s - seg_s0), 6)])
@@ -965,6 +991,7 @@ class PopulationTrial:
         lane_applied = np.zeros(k, np.int64)  # device opt.step at last retire
         budgets = np.zeros(k, np.float64)    # this round's budget (lane-local)
         resumed_at = np.zeros(k, np.int64)   # lane-local step a restore resumed from
+        job_ids = [-1] * k                   # the leased job's id (spans only)
         streams = [-(i + 1) for i in range(k)]     # idle = sentinel stream
         hps = [self._hparams({}, 0) for _ in range(k)]
         lane_keys = [self._init_key(s) for s in streams]
@@ -1039,6 +1066,22 @@ class PopulationTrial:
             boundaries=hook.boundaries if hook is not None else ())
         next_event = 0
         s_lo, s_hi = (jnp.asarray(w) for w in split_streams(streams))
+        # the open flight.event span: host work between the device results
+        # of an event step (or the end of a linger) and the next dispatch
+        event_span, event_step, n_retired, n_leased = None, 0, 0, 0
+
+        def _close_event() -> None:
+            nonlocal event_span
+            if event_span is not None:
+                tracing.record("flight.event", event_span, time.time(),
+                               step=event_step, retired=n_retired,
+                               leased=n_leased)
+                event_span = None
+
+        def _dispatch(program: str, t: int, live_n: int):
+            """A training dispatch ends the open event pass."""
+            _close_event()
+            return self._train_dispatch(program, t, k, live_n)
 
         def _next_event() -> int:
             live_now = [i for i in range(k) if handles[i] is not None]
@@ -1067,12 +1110,16 @@ class PopulationTrial:
             # budget is exhausted (incl. just-truncated) or that diverged
             if live and s >= next_event:
                 self._event_seq += 1
-                diverged = np.asarray(pstate["diverged"])
-                last = np.asarray(pstate["last_loss"])
-                # the device-side optimizer step counter is the exact number
-                # of *applied* steps — a diverged lane froze there, however
-                # late the capped divergence poll noticed it
-                applied = np.asarray(pstate["inner"]["opt"]["step"])
+                with tracing.span("flight.sync", step=s):
+                    diverged = np.asarray(pstate["diverged"])
+                    last = np.asarray(pstate["last_loss"])
+                    # the device-side optimizer step counter is the exact
+                    # number of *applied* steps — a diverged lane froze
+                    # there, however late the capped divergence poll noticed
+                    applied = np.asarray(pstate["inner"]["opt"]["step"])
+                if event_span is None:
+                    event_span = tracing.begin("flight.event")
+                    event_step, n_retired, n_leased = s, 0, 0
                 if (snap_fn is not None and self.snapshot_every
                         and self._event_seq % self.snapshot_every == 0):
                     # harvest BEFORE the retire/lease churn below: the journal
@@ -1086,8 +1133,10 @@ class PopulationTrial:
                         if (diverged[lane] or lineage[lane] is not None
                                 or local <= 0 or local >= budgets[lane]):
                             continue
-                        snap = jax.device_get(
-                            snap_fn(pstate, jnp.asarray(lane, jnp.int32)))
+                        with tracing.span("flight.lane_op", op="snapshot",
+                                          lane=lane, job_id=job_ids[lane]):
+                            snap = jax.device_get(
+                                snap_fn(pstate, jnp.asarray(lane, jnp.int32)))
                         self.n_dispatches += 1
                         self.n_snapshots += 1
                         self.snapshots.put(streams[lane], snap, {
@@ -1113,8 +1162,10 @@ class PopulationTrial:
                     local = np.array(
                         [s - starts[i] if handles[i] is not None else 0
                          for i in range(k)], np.float64)
-                    budgets = np.asarray(
-                        hook.observe(local, last, budgets, diverged), np.float64)
+                    with tracing.span("flight.rung_rule", step=s):
+                        budgets = np.asarray(
+                            hook.observe(local, last, budgets, diverged),
+                            np.float64)
                 retired: list = []  # device mode: one batch per event pass
                 for lane in live:
                     local_s = int(s - starts[lane])
@@ -1133,10 +1184,14 @@ class PopulationTrial:
                             "lane": lane,
                             "resumed_from_step": int(resumed_at[lane]),
                         }
+                        n_retired += 1
                         if batch_complete is not None:
                             retired.append((handles[lane], score, extra))
                         else:
-                            scheduler.complete(handles[lane], score, extra=extra)
+                            with tracing.span("flight.retire", lane=lane,
+                                              job_id=job_ids[lane]):
+                                scheduler.complete(handles[lane], score,
+                                                   extra=extra)
                         if self.journal is not None:
                             self.journal.append(
                                 "retire", lane=lane, step=local_s,
@@ -1162,7 +1217,8 @@ class PopulationTrial:
                     # the scan's emitted event log, settled in one call: the
                     # scheduler streams each result exactly as the host-rule
                     # path would, but with one host sync per dispatch
-                    batch_complete(retired)
+                    with tracing.span("flight.retire", lanes=len(retired)):
+                        batch_complete(retired)
                 retired_now = [i for i in range(k) if handles[i] is None
                                and i in live]
                 if retired_now and self.ladder_dispatches is None:
@@ -1174,6 +1230,11 @@ class PopulationTrial:
             # 2) lease pending proposals (parked ones first) and dispatch each
             # through its lane-lifecycle op
             pending, parked = parked + self._drain_leases(scheduler), []
+            if pending and idle_deadline is not None and event_span is None:
+                # the flight lingered empty and work arrived: the lease pass
+                # below is host work the device waits on
+                event_span = tracing.begin("flight.event")
+                event_step, n_retired, n_leased = s, 0, 0
             if pending:
                 # clones first: a clone must read its donor's round-boundary
                 # weights, so it has to execute before a same-round keep
@@ -1261,12 +1322,14 @@ class PopulationTrial:
                         if lifecycle is not None and member is not None:
                             lifecycle.bind(member, lane, epoch)
                             lineage[lane] = member
+                    lease_span = tracing.begin("flight.lease")
                     # same resolution as the serial driver: explicit stream /
                     # job id, else a distinct lease-order stream — never the
                     # lane index, which repeats across refills of one lane
                     sid = self._serial_stream_of(cfg)
                     round_steps = int(self._n_steps(cfg))
                     handles[lane] = handle
+                    job_ids[lane] = int(cfg.get("job_id", -1))
                     starts[lane] = s
                     lane_round[lane] = int(cfg.get("pbt_round", 0))
                     base_data[lane] = lane_round[lane] * round_steps
@@ -1287,9 +1350,11 @@ class PopulationTrial:
                             # snapshot's lane-local step instead of step 0
                             snap, meta = stored
                             local = int(meta["local"])
-                            pstate = restore_fn(
-                                pstate, jnp.asarray(lane, jnp.int32),
-                                jax.device_put(snap))
+                            with tracing.span("flight.lane_op", op="restore",
+                                              lane=lane, job_id=job_ids[lane]):
+                                pstate = restore_fn(
+                                    pstate, jnp.asarray(lane, jnp.int32),
+                                    jax.device_put(snap))
                             virgin = False
                             self.n_dispatches += 1
                             self.n_lane_restores += 1
@@ -1326,6 +1391,10 @@ class PopulationTrial:
                         self.journal.append(
                             "lease", job_id=cfg.get("job_id"), lane=lane,
                             step=int(s), detail={"stream": sid})
+                    n_leased += 1
+                    if lease_span is not None:
+                        tracing.record("flight.lease", lease_span, time.time(),
+                                       job_id=job_ids[lane], lane=lane)
                 # device ops: clones first (they read donor lanes, which are
                 # never splice targets), then one splice per fresh-init lane
                 if clone_jobs:
@@ -1334,8 +1403,10 @@ class PopulationTrial:
                     for lane, donor_lane, _ in clone_jobs:
                         mask[lane] = True
                         donor_idx[lane] = donor_lane
-                    pstate = clone_fn(pstate, jnp.asarray(mask),
-                                      jnp.asarray(donor_idx, jnp.int32))
+                    with tracing.span("flight.lane_op", op="clone",
+                                      lanes=len(clone_jobs)):
+                        pstate = clone_fn(pstate, jnp.asarray(mask),
+                                          jnp.asarray(donor_idx, jnp.int32))
                     virgin = False
                     self.n_clones += len(clone_jobs)
                     self.n_dispatches += 1
@@ -1353,8 +1424,10 @@ class PopulationTrial:
                         pstate = shard_population_state(pstate, mesh)
                 elif len(splice_jobs) == 1:
                     lane = splice_jobs[0]
-                    pstate = splice_fn(
-                        pstate, jnp.asarray(lane, jnp.int32), lane_keys[lane])
+                    with tracing.span("flight.lane_op", op="splice",
+                                      lane=lane, job_id=job_ids[lane]):
+                        pstate = splice_fn(pstate, jnp.asarray(lane, jnp.int32),
+                                           lane_keys[lane])
                     virgin = False
                     self.n_splices += 1
                     self.n_dispatches += 1
@@ -1363,8 +1436,10 @@ class PopulationTrial:
                     # one masked reset beats a dispatch per lane
                     reset_mask = np.zeros(k, bool)
                     reset_mask[splice_jobs] = True
-                    pstate = init_fn(
-                        pstate, jnp.asarray(reset_mask), jnp.stack(lane_keys))
+                    with tracing.span("flight.lane_op", op="init",
+                                      lanes=len(splice_jobs)):
+                        pstate = init_fn(pstate, jnp.asarray(reset_mask),
+                                         jnp.stack(lane_keys))
                     virgin = False
                     self.n_dispatches += 1
                 live = [i for i in range(k) if handles[i] is not None]
@@ -1407,6 +1482,7 @@ class PopulationTrial:
                         return out
 
                     handles = _gather(handles, lambda j: None)
+                    job_ids = _gather(job_ids, lambda j: -1)
                     used = _gather(used, lambda j: True)
                     lineage = _gather(lineage, lambda j: None)
                     lane_round = _gather(lane_round, lambda j: 0)
@@ -1430,19 +1506,23 @@ class PopulationTrial:
                     self.n_regrids += 1
                     self.lane_width_history.append([int(k2), int(width)])
             if php_dirty:
-                php = stack_hparams(hps)
-                s_lo, s_hi = (jnp.asarray(w) for w in split_streams(streams))
-                if ring is not None:
-                    # lane table changed: re-key the ring so lane i's slab at
-                    # global step s' is its own stream at base_data + s' -
-                    # starts (idle lanes fill from their sentinel stream —
-                    # masked lanes never apply those batches)
-                    offs = [int(base_data[i] - starts[i])
-                            if handles[i] is not None else 0 for i in range(k)]
-                    ring.set_lanes(streams, offs, at_step=s)
+                with tracing.span("flight.restack", step=s):
+                    php = stack_hparams(hps)
+                    s_lo, s_hi = (jnp.asarray(w)
+                                  for w in split_streams(streams))
+                    if ring is not None:
+                        # lane table changed: re-key the ring so lane i's
+                        # slab at global step s' is its own stream at
+                        # base_data + s' - starts (idle lanes fill from their
+                        # sentinel stream — masked lanes never apply them)
+                        offs = [int(base_data[i] - starts[i])
+                                if handles[i] is not None else 0
+                                for i in range(k)]
+                        ring.set_lanes(streams, offs, at_step=s)
             if not live:
                 # 3) flight idle: linger briefly for late proposals (Algorithm 1
                 # may be mid-callback), then return the lanes
+                _close_event()
                 if getattr(scheduler, "closed", False) and not parked:
                     break
                 now = _time.time()
@@ -1457,7 +1537,8 @@ class PopulationTrial:
                         idle_deadline = None
                         continue
                     break
-                _time.sleep(0.002)
+                with tracing.span("flight.linger", step=s):
+                    _time.sleep(0.002)
                 continue
             idle_deadline = None
             next_event = _next_event()
@@ -1481,6 +1562,7 @@ class PopulationTrial:
                 # different chunk split would reorder result arrival under a
                 # stateful proposer and break engine score-equivalence
                 ring.wait_filled(s, t)
+            live_n = sum(1 for i in live if starts[i] + budgets[i] > s)
             if device_active:
                 # rule-carrying scan (any t >= 1): budgets ride as scan state,
                 # rung cuts / window verdicts land in-scan, and the emitted
@@ -1510,9 +1592,10 @@ class PopulationTrial:
                         budgets, applied0, local0,
                         lifecycle.quantile, wscore, len(wentries))
                     mode = "pbt"
-                (pstate, rout), _ = rule_scan_of(t, mode)(
-                    pstate, php, jnp.asarray(steps0, jnp.int32), s_lo, s_hi,
-                    rules)
+                with _dispatch("rule_scan", t, live_n):
+                    (pstate, rout), _ = rule_scan_of(t, mode)(
+                        pstate, php, jnp.asarray(steps0, jnp.int32), s_lo,
+                        s_hi, rules)
                 virgin = False
                 if device_spec is not None:
                     new_budgets = np.asarray(rout["budgets"], np.float64)
@@ -1537,7 +1620,7 @@ class PopulationTrial:
                 # ring-fed fused scan: slabs for steps [s, s+t) are already on
                 # device (wait_filled capped t), so the per-lane cursors ride
                 # in the ring contents, not in traced stream words
-                with ring.reserve() as slots:
+                with ring.reserve() as slots, _dispatch("ring_scan", t, live_n):
                     pstate, _ = ring_scan_of(t)(
                         pstate, php, slots,
                         jnp.asarray(s % ring.capacity, jnp.int32))
@@ -1546,20 +1629,22 @@ class PopulationTrial:
                 for i in range(k):
                     if handles[i] is not None:
                         steps0[i] = base_data[i] + s - starts[i]
-                pstate, _ = scan_of(t)(
-                    pstate, php, jnp.asarray(steps0, jnp.int32), s_lo, s_hi)
+                with _dispatch("scan", t, live_n):
+                    pstate, _ = scan_of(t)(
+                        pstate, php, jnp.asarray(steps0, jnp.int32), s_lo,
+                        s_hi)
             else:
                 # one vectorized synthesis call for all K lanes (idle lanes
                 # consume their sentinel stream at step 0 — never applied)
                 cursors = [int(base_data[i] + s - starts[i])
                            if handles[i] is not None else 0 for i in range(k)]
                 batch = data.make_population_batch(cursors, streams)
-                pstate, _ = pstep(pstate, batch, php)
-            self.n_dispatches += 1
-            self.n_train_steps += t
+                with _dispatch("step", t, live_n):
+                    pstate, _ = pstep(pstate, batch, php)
             s += t
             if ring is not None:
                 ring.consume_to(s)
+        _close_event()
         if ring is not None:
             self._absorb_ring(ring)
         self.last_flight_steps = s
@@ -1826,6 +1911,11 @@ def main(argv=None) -> int:
                    help="deterministic fault injection, e.g. 'raise@step=20' "
                         "or 'kill@event=3' (see repro.core.faultinject; also "
                         "armable via REPRO_FAULT_SPEC)")
+    p.add_argument("--profile-dir", default="", metavar="DIR",
+                   help="run the job inside jax.profiler.trace(DIR): the "
+                        "program's spans (repro.core.tracing) land beside the "
+                        "device's ops in one trace that TensorBoard or "
+                        "Perfetto opens")
     args = p.parse_args(argv)
 
     from ..core import faultinject
@@ -2039,7 +2129,17 @@ def main(argv=None) -> int:
     if args.device_rules and args.pbt_streaming:
         # switch decide() to consume scan-emitted window-quantile verdicts
         exp.proposer.lifecycle_hook().enable_device_rule()
-    best = exp.run()
+    profile = contextlib.nullcontext()
+    if args.profile_dir:
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0  # the spans name the host work
+        profile = jax.profiler.trace(args.profile_dir,
+                                     create_perfetto_trace=True,
+                                     profiler_options=opts)
+    with profile:
+        best = exp.run()
     dt = time.time() - t0
     engine = ("legacy-recompile" if args.legacy_recompile else
               "serial" if args.vectorize == 0 else
@@ -2071,6 +2171,10 @@ def main(argv=None) -> int:
         out["trained_steps"] = trained
         out["dispatches_per_step"] = (
             round(trial.n_dispatches / trained, 3) if trained else None)
+        # % of dispatched lane-steps whose lane held a trial with budget left
+        out["lane_occupancy"] = (
+            round(100.0 * trial.n_live_lane_steps / trial.n_lane_steps, 2)
+            if trial.n_lane_steps else None)
     if args.data_ring:
         out["ring_windows"] = args.ring_windows
         out["ring_fills"] = trial.n_ring_fills
