@@ -128,6 +128,8 @@ def synth_tokens(xp, spec: "SyntheticLM", rows_shape, step, stream_lo,
     vocab = int(spec.vocab_size)
     row = xp.arange(rows_shape[-1], dtype=xp.uint32)
     coords = [spec.seed, stream_lo, stream_hi, step, shard, row]
+    if xp is np:
+        return _synth_tokens_host(spec, tuple(rows_shape), coords)
 
     def draw(kind, t):
         return _hash_u32(xp, rows_shape, [kind] + coords + [t])
@@ -142,12 +144,6 @@ def synth_tokens(xp, spec: "SyntheticLM", rows_shape, step, stream_lo,
         follow = _u01(xp, draw(_KIND_FOLLOW, t)) < mix
         return xp.where(follow, _rule32(xp, a, b, vocab), tok(_KIND_NOISE, t))
 
-    if xp is np:
-        toks = np.empty(rows_shape + (spec.seq_len + 1,), np.int32)
-        toks[..., 0], toks[..., 1] = t0, t1
-        for t in range(2, spec.seq_len + 1):
-            toks[..., t] = next_tok(toks[..., t - 2], toks[..., t - 1], t)
-        return toks
     import jax
 
     def body(carry, t):
@@ -159,6 +155,64 @@ def synth_tokens(xp, spec: "SyntheticLM", rows_shape, step, stream_lo,
     _, rest = jax.lax.scan(body, (t0, t1), ts)
     rest = xp.moveaxis(rest, 0, -1)  # (T-2,) + rows -> rows + (T-2,)
     return xp.concatenate([t0[..., None], t1[..., None], rest], axis=-1)
+
+
+def _synth_tokens_host(spec: "SyntheticLM", rows_shape, coords) -> np.ndarray:
+    """``synth_tokens`` with NumPy, bit-identical to the traced scan.
+
+    Only the rule hash depends on earlier tokens. The INIT, FOLLOW and NOISE
+    draws are pure functions of their coordinates, so each is hashed for
+    every position at once over a trailing position axis. The loop over
+    positions keeps only the rule hash, written out in place with
+    ``_hash_u32``'s constants and order: a per-position ``_hash_u32`` call
+    spends its time in NumPy's per-call overhead on arrays of a few rows.
+    Tokens are kept as uint32 and returned as an int32 view of the same bits
+    (``astype(int32)`` and back, as the scan does, is the same wrap).
+    """
+    vocab = np.uint32(int(spec.vocab_size))
+    n = int(spec.seq_len) + 1
+    wide = [w if isinstance(w, (int, np.integer)) else np.asarray(w)[..., None]
+            for w in coords]
+
+    def draw_all(kind, width):
+        t = np.arange(width, dtype=np.uint32)
+        return _hash_u32(np, rows_shape + (width,), [kind] + wide + [t])
+
+    toks = draw_all(_KIND_NOISE, n) % vocab
+    toks[..., :2] = draw_all(_KIND_INIT, 2) % vocab
+    follow = _u01(np, draw_all(_KIND_FOLLOW, n)) < np.float32(spec.order_mix)
+
+    u = np.uint32
+    c1, c2, five = u(0xCC9E2D51), u(0xE6546B64), u(5)
+    f1, f2, h0 = u(0x85EBCA6B), u(0xC2B2AE35), u(0x9E3779B9)
+    h = np.empty(rows_shape, np.uint32)
+    tmp = np.empty_like(h)
+
+    def absorb(w):  # h = rotl13(h ^ w * c1) * 5 + c2
+        np.multiply(w, c1, out=tmp)
+        np.bitwise_xor(h, tmp, out=h)
+        np.left_shift(h, u(13), out=tmp)
+        np.right_shift(h, u(19), out=h)
+        np.bitwise_or(h, tmp, out=h)
+        np.multiply(h, five, out=h)
+        np.add(h, c2, out=h)
+
+    def fold(shift):  # h ^= h >> shift
+        np.right_shift(h, u(shift), out=tmp)
+        np.bitwise_xor(h, tmp, out=h)
+
+    for t in range(2, n):
+        h.fill(h0)
+        absorb(toks[..., t - 2])
+        absorb(toks[..., t - 1])
+        fold(16)
+        np.multiply(h, f1, out=h)
+        fold(13)
+        np.multiply(h, f2, out=h)
+        fold(16)
+        np.remainder(h, vocab, out=h)
+        np.copyto(toks[..., t], h, where=follow[..., t])
+    return toks.view(np.int32)
 
 
 def tokens_to_batch(xp, spec: "SyntheticLM", toks) -> Dict[str, Any]:
