@@ -7,36 +7,84 @@ share; one that does less cannot pass 100%.
 
 Conventions: a multiply-add is 2 operations; a training step is the forward
 pass and a backward pass of twice its matmul work; causal attention needs
-half of the full score matrix.  Bytes are float32 unless ``dtype_bytes``
+half of the full score matrix.  The count walks the configuration's layer
+pattern (``modelfile.layers``), each kind with its own terms; a kind without
+terms is an error, not a dense guess.  Bytes are float32 unless ``dtype_bytes``
 says otherwise.
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
+
+from .modelfile import layers, published
 
 
-def _dims(cfg: Dict[str, Any]):
-    d, h, kv, hd = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    ff, v = cfg["d_ff"], cfg["vocab_size"]
-    mats = 2 if cfg["activation"] == "swiglu" else 1
-    return d, h, kv, hd, ff, v, mats
+def _mats(cfg: Dict[str, Any]) -> int:
+    return 2 if cfg["activation"] == "swiglu" else 1
 
 
-def matmul_params(cfg: Dict[str, Any]) -> int:
+def _attention_widths(cfg: Dict[str, Any], mixer: str) -> Tuple[int, int, int, int]:
+    """(query heads, key/value heads, q and k width, v and out width)."""
+    h, hd = cfg["n_heads"], cfg["head_dim"]
+    if mixer == "attn":
+        return h, cfg["n_kv_heads"], hd, hd
+    if mixer == "attn_mla":
+        # models/attention.py projects q with one matrix: no q LoRA to count
+        if cfg.get("q_lora_rank"):
+            raise ValueError("attn_mla with q_lora_rank > 0 is not what the "
+                             "program runs")
+        # keys are decompressed per head: as many k/v heads as query heads
+        return h, h, hd + cfg["rope_head_dim"], hd
+    raise ValueError(f"no FLOP count for mixer {mixer!r}")
+
+
+def _mixer_params(cfg: Dict[str, Any], mixer: str) -> float:
+    d, h, hd = cfg["d_model"], cfg["n_heads"], cfg["head_dim"]
+    if mixer == "attn_mla":
+        _, _, qk, _ = _attention_widths(cfg, mixer)
+        r, rr = cfg["kv_lora_rank"], cfg["rope_head_dim"]
+        # q; the latent and the shared rope key; k_nope and v up; out
+        return d * h * qk + d * (r + rr) + r * h * 2 * hd + h * hd * d
+    _, kv, _, _ = _attention_widths(cfg, mixer)
+    return d * (h + 2 * kv) * hd + h * hd * d
+
+
+def _ffn_params(cfg: Dict[str, Any], ffn: str) -> float:
+    d, up_down = cfg["d_model"], _mats(cfg) + 1
+    if ffn == "dense":
+        return up_down * d * cfg["d_ff"]
+    if ffn == "moe":
+        # the router spans every published expert; a token's top-k routed
+        # experts lie here in the share of them that this chip holds.
+        # Capacity padding is not work of the yardstick.
+        routed = published(cfg, "n_experts")
+        expert = up_down * d * cfg["moe_d_ff"]
+        return (d * routed + cfg["n_shared_experts"] * expert
+                + cfg["moe_top_k"] * cfg["n_experts"] / routed * expert)
+    raise ValueError(f"no FLOP count for ffn {ffn!r}")
+
+
+def matmul_params(cfg: Dict[str, Any]) -> float:
     """Weights that multiply every token once per forward pass (the input
-    embedding is a gather, the tied output head a matmul)."""
-    d, h, kv, hd, ff, v, mats = _dims(cfg)
-    per_layer = d * (h + 2 * kv) * hd + h * hd * d + (mats + 1) * d * ff
-    return cfg["n_layers"] * per_layer + v * d
+    embedding is a gather, the output head a matmul), layer by layer."""
+    return (sum(_mixer_params(cfg, m) + _ffn_params(cfg, f)
+                for m, f in layers(cfg))
+            + cfg["vocab_size"] * cfg["d_model"])
+
+
+def _pairs(seq: int, causal: bool) -> float:
+    return seq * (seq + 1) / 2 if causal else seq * seq
 
 
 def attention_forward_flops(cfg: Dict[str, Any], seq: int,
                             causal: bool = True) -> float:
     """Scores and weighted values of one sequence, all layers: the causal
     half of the (query, key) pairs, or all of them."""
-    _, h, _, hd, _, _, _ = _dims(cfg)
-    pairs = seq * (seq + 1) / 2 if causal else seq * seq
-    return cfg["n_layers"] * 2 * 2 * h * hd * pairs
+    total = 0.0
+    for mixer, _ in layers(cfg):
+        h, _, qk, v = _attention_widths(cfg, mixer)
+        total += 2 * h * (qk + v) * _pairs(seq, causal)
+    return total
 
 
 def train_step_flops(cfg: Dict[str, Any], seq: int, batch: int = 1,
@@ -51,10 +99,14 @@ def flash_attention_call(cfg: Dict[str, Any], seq: int, rows: int,
                          dtype_bytes: int = 4) -> Dict[str, float]:
     """One forward flash-attention call over ``rows`` sequences (lanes x
     batch) of one layer: causal FLOPs, and q, k, v read plus out and the
-    log-sum-exp column written."""
-    _, h, kv, hd, _, _, _ = _dims(cfg)
-    flops = rows * 2 * 2 * h * hd * seq * (seq + 1) / 2
-    elems = rows * seq * (2 * h * hd + 2 * kv * hd)
+    log-sum-exp column written.  The layers' one attention kind sets the
+    widths."""
+    mixers = {m for m, _ in layers(cfg)}
+    if len(mixers) != 1:
+        raise ValueError(f"one flash-attention call per kind: {sorted(mixers)}")
+    h, kv, qk, v = _attention_widths(cfg, mixers.pop())
+    flops = rows * 2 * h * (qk + v) * seq * (seq + 1) / 2
+    elems = rows * seq * (h * qk + kv * qk + kv * v + h * v)
     return {"flops": flops,
             "bytes": elems * dtype_bytes + rows * seq * h * 4}
 

@@ -19,12 +19,10 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from . import modelfile as M
 from . import spec as S
 from . import trace as TR
 
-MODEL_KEYS = ("d_model", "n_layers", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab_size", "activation", "tie_embeddings",
-              "rope_theta", "norm_eps", "param_dtype", "compute_dtype")
 TRACE_DIR = os.path.join(S.REPO_ROOT, ".bench_trace")
 
 
@@ -86,14 +84,6 @@ class CompileCounter:
 
 
 # -- the system under test -------------------------------------------------------
-def model_overrides(config: Dict[str, Any], control: bool) -> Dict[str, Any]:
-    over = {k: config[k] for k in MODEL_KEYS}
-    over["name"] = config["name"]
-    if control:
-        over.update(config["control"])
-    return over
-
-
 def build_trial(cell: S.Cell, seed: int, control: bool = False):
     from repro.launch.hpo import PopulationTrial
 
@@ -102,7 +92,7 @@ def build_trial(cell: S.Cell, seed: int, control: bool = False):
         cell.config["preset"], tr["steps_per_unit"], tr["batch"], tr["seq"],
         seed, population=tr["lanes"], chunk_steps=tr["chunk_steps"],
         model_parallel=tr.get("model_parallel", 1),
-        model_overrides=model_overrides(cell.config, control))
+        model_overrides=M.model_overrides(cell.config, control))
 
 
 def build_experiment(cell: S.Cell, trial, seed: int, n_samples: int):

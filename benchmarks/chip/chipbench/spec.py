@@ -10,7 +10,8 @@ limits or one per-layer metric sits in a file of its own, found by name:
     references/<reference>.py   the plain reference a configuration names
 
 so a cell, a configuration or a metric is added without editing a file that
-is already there.
+is already there.  A configuration resolves only where it states the model
+its trial builds (``modelfile.py``).
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ import importlib.util
 import json
 import os
 from typing import Any, Callable, Dict, List, Optional
+
+from . import modelfile
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO_ROOT = os.path.dirname(os.path.dirname(BENCH_DIR))
@@ -64,6 +67,7 @@ def resolve(cell_name: str, bench: Optional[Dict[str, Any]] = None) -> Cell:
     cfg_entry = configs[w["config"]]
     config = _json(os.path.relpath(os.path.join(REPO_ROOT, cfg_entry["file"]),
                                    BENCH_DIR))
+    modelfile.check(config, cfg_entry["file"])
     e2e = [m for m in bench["end_to_end"]
            if cell_name in m.get("workloads", [cell_name])]
     reported = [m["name"] for m in e2e]
